@@ -819,14 +819,14 @@ def bench_train_step_time(ways: int = 8) -> list:
         params = init_params(gcn_schema(cfg), jax.random.PRNGKey(0))
         state = {"params": params, "opt": adamw_init(params, tc),
                  "step": jnp.zeros((), jnp.int32)}
-        step = jax.jit(make_sage_train_step(cfg, tc, feats=feats, mesh=mesh))
-        state, m = step(state, batch)            # compile + warm
+        step = jax.jit(make_sage_train_step(cfg, tc, mesh=mesh))
+        state, m = step(state, batch, feats)            # compile + warm
         jax.block_until_ready(state)
         runs[key] = {"step": step, "state": state,
                      "loss": float(m["total_loss"])}
 
     def run_one(r):
-        r["state"], _ = r["step"](r["state"], batch)
+        r["state"], _ = r["step"](r["state"], batch, feats)
         jax.block_until_ready(r["state"])
 
     best = _interleaved_min_us(runs, run_one, trials=7, reps=3)

@@ -152,16 +152,19 @@ def bench_collective_bytes(fast=False):
         out_path = os.path.join(here, "..", "BENCH_collective_bytes.json")
     cmd = [sys.executable, os.path.join(here, "collective_bytes.py"),
            "--out", out_path] + (["--fast"] if fast else [])
+    # the child counts HLO bytes on 8 virtual CPU devices; it never needs
+    # (and must not claim) the accelerator this process may already hold
     proc = subprocess.run(
         cmd, capture_output=True, text=True,
         env={**os.environ,
+             "JAX_PLATFORMS": "cpu",
              "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
              "PYTHONPATH": os.path.join(here, "..", "src")})
     try:
         if proc.returncode != 0 or not os.path.exists(out_path):
             tail = (proc.stderr.strip().splitlines() or ["?"])[-1]
-            print(f"collective_bytes,ERROR,exit={proc.returncode}:{tail}")
-            return
+            raise RuntimeError(f"collective_bytes.py exit="
+                               f"{proc.returncode}: {tail}")
         with open(out_path) as f:
             data = json.load(f)
     finally:
@@ -295,20 +298,25 @@ BENCHES = {
 }
 
 
-def main() -> None:
+def main() -> int:
+    """Runs every bench (or ``--only`` one); exit 1 if any of them raised,
+    after the rest have run and each failure has printed its ERROR row."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in BENCHES.items():
         if args.only and args.only != name:
             continue
         try:
             fn(fast=args.fast)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — reported, and fails the run
             print(f"{name},ERROR,{type(e).__name__}:{e}")
+            failed.append(name)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

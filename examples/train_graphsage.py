@@ -1,5 +1,5 @@
 """End-to-end driver (the paper's workload): distributed GraphSAGE training
-with the CGTrans dataflow on an 8-shard storage mesh.
+with the CGTrans dataflow on a storage mesh of one shard per device.
 
 Features live owner-sharded on the mesh (never shipped raw); batches carry
 only vertex ids; layer-1 aggregation happens at the owner shards and only the
@@ -7,13 +7,18 @@ compressed partials cross the interconnect. Full production loop: AdamW +
 cosine, checkpointing + resume, straggler monitor, preemption guard.
 
     PYTHONPATH=src python examples/train_graphsage.py --steps 300
+
+On the CPU (``JAX_PLATFORMS=cpu``, the rehearsal) the script gives itself 8
+virtual devices; on accelerators it shards over the chips JAX finds.
 """
 
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import argparse
-import functools
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +30,7 @@ from repro.common.schema import count_params, init_params
 from repro.core.gcn import GCNConfig, gcn_schema, sage_loss
 from repro.data import GraphBatchStream, synthetic_node_labels
 from repro.graph import partition_by_src, rmat
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_data_mesh
 from repro.optim import adamw_init
 from repro.runtime import PreemptionGuard, StepMonitor
@@ -55,10 +61,13 @@ def main():
                          "aggregation as two separate request streams "
                          "(the legacy two-body form) instead of ONE "
                          "coalesced SSD command block")
-    ap.add_argument("--ckpt-dir", default="/tmp/graphsage_ckpt")
+    ap.add_argument("--ckpt-dir", default=os.path.join(tempfile.gettempdir(),
+                                                       "graphsage_ckpt"))
     args = ap.parse_args()
+    enable_compile_cache()
 
-    mesh = make_data_mesh(8)
+    n_shards = jax.device_count()
+    mesh = make_data_mesh(n_shards)
     print(f"mesh: {mesh.shape} (storage tier = 'data' axis)")
 
     g = rmat(args.scale, 16, seed=0)
@@ -66,12 +75,12 @@ def main():
     g.features = rng.standard_normal(
         (g.n_vertices, args.features)).astype(np.float32)
     labels = synthetic_node_labels(g.features, 16)
-    pg = partition_by_src(g, 8)
+    pg = partition_by_src(g, n_shards)
     feats = jax.device_put(
         jnp.asarray(pg.features),
         jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data")))
     print(f"graph: {g.n_vertices} vertices, {g.n_edges} edges; "
-          f"features owner-sharded {pg.features.shape} over 8 shards")
+          f"features owner-sharded {pg.features.shape} over {n_shards} shards")
 
     cfg = GCNConfig(n_features=args.features, hidden=args.hidden, n_classes=16,
                     fanout=args.fanout, dataflow=args.dataflow,
@@ -84,11 +93,11 @@ def main():
           f"(+{feats.size / 1e6:.1f}M feature table on the storage tier), "
           f"dataflow={args.dataflow} impl={args.impl}")
 
-    stream = GraphBatchStream(g, labels, n_parts=8,
+    stream = GraphBatchStream(g, labels, n_parts=n_shards,
                               batch_per_part=args.batch_per_part,
                               k1=args.fanout, k2=args.fanout)
 
-    step = jax.jit(make_sage_train_step(cfg, tc, feats=feats, mesh=mesh))
+    step = jax.jit(make_sage_train_step(cfg, tc, mesh=mesh))
 
     state = {"params": params, "opt": adamw_init(params, tc),
              "step": jnp.zeros((), jnp.int32)}
@@ -98,7 +107,8 @@ def main():
             yield {k: jnp.asarray(v) for k, v in b.items()}
 
     state, n = train_loop(
-        step_fn=step, state=state, batches=batches(),
+        step_fn=lambda st, b: step(st, b, feats), state=state,
+        batches=batches(),
         total_steps=args.steps,
         ckpt=CheckpointManager(args.ckpt_dir, keep=2), ckpt_every=100,
         monitor=StepMonitor(), guard=PreemptionGuard(), log_every=20)

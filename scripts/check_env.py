@@ -18,6 +18,9 @@ import json
 import os
 import sys
 
+#: the one JAX release ``repro.compat`` is written for
+SUPPORTED_JAX = "0.9.0"
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -48,17 +51,13 @@ def main(argv=None) -> int:
     try:
         from repro import compat
     except ImportError as e:
-        if "shard_map" in str(e):
-            # compat itself raised importing shard_map: JAX predates even
-            # jax.experimental.shard_map — older than the supported range
-            print("the installed JAX has no shard_map anywhere (neither "
-                  "jax.shard_map nor jax.experimental.shard_map) — older "
-                  f"than the supported >=0.4.30 range; upgrade jax ({e})",
-                  file=sys.stderr)
-        else:
-            print(f"cannot import repro.compat — is PYTHONPATH=src set? ({e})",
-                  file=sys.stderr)
+        print(f"cannot import repro.compat — is PYTHONPATH=src set? ({e})",
+              file=sys.stderr)
         return 1
+    if compat.FEATURES["jax_version"] != SUPPORTED_JAX:
+        failures.append(
+            f"repro.compat is written for jax {SUPPORTED_JAX}; "
+            f"jax {compat.FEATURES['jax_version']} is installed")
 
     for key, val in compat.feature_matrix().items():
         rows.append((f"compat.{key}", str(val)))

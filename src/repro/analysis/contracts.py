@@ -393,12 +393,9 @@ def _build_train_step(impl: str, coalesce: bool, scheduled: bool):
             lambda a: _sds(jnp.shape(a), jnp.result_type(a)),
             {"params": params, "opt": adamw_init(params, tc),
              "step": jnp.zeros((), jnp.int32)})
-        # feats closes over as a CONCRETE constant (the API takes it that
-        # way); zeros are fine — nothing executes under make_jaxpr
-        step = make_sage_train_step(
-            cfg, tc, feats=jnp.zeros((_WAYS, _PART, cfg.n_features)),
-            mesh=mesh)
-        return step, (state, batch)
+        step = make_sage_train_step(cfg, tc, mesh=mesh)
+        feats = _sds((_WAYS, _PART, cfg.n_features), jnp.float32)
+        return step, (state, batch, feats)
     return build
 
 

@@ -1,169 +1,65 @@
-"""Version-portability layer for every JAX API this repo uses that drifted
-across releases. All version-sensitive imports live HERE and nowhere else —
-call sites import ``shard_map``/``make_mesh``/``AxisType``/``psum_scatter``
-from ``repro.compat`` and never touch ``jax.shard_map``,
-``jax.sharding.AxisType`` or ``axis_types=`` directly.
+"""The single door for every JAX API this repo uses whose spelling has moved
+between releases. Call sites import ``shard_map``/``make_mesh``/
+``AxisType``/``psum_scatter`` from ``repro.compat`` and never touch
+``jax.shard_map``, ``jax.sharding.AxisType`` or ``axis_types=`` directly, so
+the next move is a one-file change (the ``compat-door`` lint enforces it).
 
-Covered drift (JAX 0.4.x → current):
-
-* ``shard_map`` — promoted from ``jax.experimental.shard_map.shard_map`` to
-  top-level ``jax.shard_map``; its replication-check kwarg was renamed
-  ``check_rep`` → ``check_vma`` along the way. The wrapper takes the modern
-  keyword-only signature and translates down.
-* mesh construction — ``jax.make_mesh`` appeared in 0.4.35 and grew an
-  ``axis_types=`` kwarg later; before either, meshes were built as
-  ``Mesh(mesh_utils.create_device_mesh(shape), names)``. ``make_mesh`` here
-  accepts ``axis_types`` always and silently drops it when the installed JAX
-  cannot express it (pre-AxisType meshes behave as Auto everywhere, which is
-  exactly what this repo requests).
-* ``jax.sharding.AxisType`` — absent before sharding-in-types; a stub enum
-  with the same member names keeps call sites one-sourced.
-* ``lax.psum_scatter`` — present throughout the supported range but guarded
-  anyway; the fallback is the semantically-identical (if uncompressed)
-  psum + owned-slice, so CGTrans still *computes* correctly on a JAX that
-  lacks the fused collective (the collective-bytes benches will simply show
-  the all-reduce cost).
-
-``FEATURES`` records what was detected; ``scripts/check_env.py`` prints it as
-a support matrix and fails fast with an actionable message instead of letting
-12 test modules error at collection/runtime.
+Written for the installed JAX (0.9.0) only; there are no branches for other
+versions. ``FEATURES`` records what is in use; ``scripts/check_env.py``
+prints it as the support matrix.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 from jax import lax
-from jax.experimental import mesh_utils
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-FEATURES: Dict[str, object] = {"jax_version": jax.__version__}
+FEATURES: Dict[str, object] = {
+    "jax_version": jax.__version__,
+    "shard_map": "jax.shard_map(check_vma=...)",
+    "make_mesh": "jax.make_mesh(axis_types=...)",
+    "axis_type": "jax.sharding.AxisType",
+    "psum_scatter": "lax.psum_scatter",
+}
 
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-    FEATURES["shard_map_source"] = "jax.shard_map"
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    FEATURES["shard_map_source"] = "jax.experimental.shard_map"
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-FEATURES["shard_map_check_kwarg"] = (
-    "check_vma" if "check_vma" in _SHARD_MAP_PARAMS
-    else "check_rep" if "check_rep" in _SHARD_MAP_PARAMS else None)
+psum_scatter = lax.psum_scatter
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """Version-portable ``shard_map``. Modern keyword-only calling convention;
-    ``check_vma`` maps onto ``check_rep`` on older JAX (same meaning: verify
-    the per-shard replication/varying-manual-axes annotation). ``None`` keeps
-    the installed default."""
-    kwargs = {}
-    if check_vma is not None and FEATURES["shard_map_check_kwarg"]:
-        kwargs[FEATURES["shard_map_check_kwarg"]] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# AxisType
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.sharding import AxisType  # noqa: F401  (JAX ≥ 0.5-era)
-    FEATURES["axis_type"] = "jax.sharding.AxisType"
-except ImportError:
-    import enum
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stub with the real member names; pre-AxisType meshes implicitly
-        treat every axis as Auto, so dropping these is lossless for us."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    FEATURES["axis_type"] = "repro.compat stub"
-
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
-
-_HAS_MAKE_MESH = hasattr(jax, "make_mesh")
-_MAKE_MESH_AXIS_TYPES = (
-    _HAS_MAKE_MESH and "axis_types" in inspect.signature(jax.make_mesh).parameters)
-FEATURES["make_mesh"] = (
-    "jax.make_mesh(axis_types=...)" if _MAKE_MESH_AXIS_TYPES
-    else "jax.make_mesh" if _HAS_MAKE_MESH
-    else "Mesh(mesh_utils.create_device_mesh(...))")
+    """``jax.shard_map`` with its keyword-only convention; ``None`` keeps the
+    installed default of the replication (varying-manual-axes) check."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types: Optional[Tuple] = None, devices=None) -> Mesh:
-    """Build a ``Mesh``, expressing ``axis_types`` only where the installed
-    JAX can. Falls back to ``mesh_utils.create_device_mesh`` pre-0.4.35."""
-    axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    if _MAKE_MESH_AXIS_TYPES and axis_types is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=axis_types, devices=devices)
-    if _HAS_MAKE_MESH:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-    dev = mesh_utils.create_device_mesh(axis_shapes, devices=devices)
-    return Mesh(dev, axis_names)
+    """``jax.make_mesh``; ``axis_types=None`` keeps JAX's default types."""
+    kwargs = {} if axis_types is None else {"axis_types": axis_types}
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         devices=devices, **kwargs)
 
 
 # ---------------------------------------------------------------------------
-# collectives
+# collective primitive NAMES (the trace side of the same single-door rule)
 # ---------------------------------------------------------------------------
 
-if hasattr(lax, "psum_scatter"):
-    psum_scatter = lax.psum_scatter
-    FEATURES["psum_scatter"] = "lax.psum_scatter"
-else:
-    def psum_scatter(x, axis_name, *, scatter_dimension: int = 0,
-                     tiled: bool = False):
-        """Emulation: all-reduce then keep this shard's owned block. Same
-        result (and gradient) as the fused reduce-scatter, without the
-        bandwidth saving — correctness fallback only."""
-        summed = lax.psum(x, axis_name)
-        n = lax.psum(1, axis_name)          # static axis size
-        i = lax.axis_index(axis_name)
-        size = x.shape[scatter_dimension] // n if tiled else 1
-        out = lax.dynamic_slice_in_dim(summed, i * size, size,
-                                       axis=scatter_dimension)
-        if not tiled:
-            out = lax.squeeze(out, (scatter_dimension,))
-        return out
-
-    FEATURES["psum_scatter"] = "repro.compat psum+slice emulation"
-
-
-# ---------------------------------------------------------------------------
-# collective primitive NAMES (jaxpr spellings drift across JAX versions)
-# ---------------------------------------------------------------------------
-
-#: canonical collective name → every jaxpr primitive spelling that means it.
-#: The *API* drift is handled above (``psum_scatter``); this is the *trace*
-#: side of the same single-door rule: ``lax.psum_scatter`` lowers to a
-#: primitive literally named ``reduce_scatter``, ``lax.ppermute`` to
-#: ``ppermute`` or ``collective_permute`` depending on version, and
-#: ``shard_map``'s replication checker rewrites ``psum`` to ``psum2``
-#: (0.4.3x-era; later versions went back to ``psum``). Anything that reads
-#: jaxprs (``launch/jaxpr_stats``, ``analysis/contracts``) counts under the
-#: canonical key so committed budgets survive version bumps.
+#: canonical collective name → every jaxpr primitive spelling that means it
+#: (the canonical name first). ``lax.psum_scatter`` traces as
+#: ``reduce_scatter`` and a ``psum`` inside ``shard_map`` as
+#: ``psum_invariant``.
+#: Anything that reads jaxprs (``launch/jaxpr_stats``, ``analysis/contracts``)
+#: counts under the canonical key so committed budgets survive a rename.
 COLLECTIVE_ALIASES: Dict[str, Tuple[str, ...]] = {
     "all_to_all": ("all_to_all",),
     "all_gather": ("all_gather",),
-    "psum": ("psum", "psum2"),
+    "psum": ("psum", "psum_invariant"),
     "psum_scatter": ("psum_scatter", "reduce_scatter"),
-    "ppermute": ("ppermute", "collective_permute"),
+    "ppermute": ("ppermute",),
     "pmax": ("pmax",),
     "pmin": ("pmin",),
 }
@@ -182,5 +78,5 @@ def canonical_collective(primitive_name: str) -> Optional[str]:
 
 
 def feature_matrix() -> Dict[str, object]:
-    """Snapshot of what the compat layer detected on the installed JAX."""
+    """Snapshot of the JAX APIs the compat layer routes to."""
     return dict(FEATURES)

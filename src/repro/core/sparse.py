@@ -115,6 +115,16 @@ def density_stats(x) -> dict:
             "density": (nnz / total) if total else 0.0}
 
 
+def _nonzero(x: jnp.ndarray) -> jnp.ndarray:
+    """Occupancy from the bit pattern, sign bit aside: XLA flushes
+    subnormals to zero in a float compare, which would drop them from the
+    packed row while the dense path (a plain copy) keeps them. Matches
+    numpy's ``x != 0`` in ``table_capacity`` (-0.0 counts as zero)."""
+    bits = x.dtype.itemsize * 8
+    u = lax.bitcast_convert_type(x, jnp.dtype(f"uint{bits}"))
+    return (u & jnp.asarray((1 << (bits - 1)) - 1, u.dtype)) != 0
+
+
 def encode_rows(x: jnp.ndarray, capacity: int
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(…, F) rows → (packed (…, capacity) in x's dtype, bitmap (…, W)
@@ -126,7 +136,7 @@ def encode_rows(x: jnp.ndarray, capacity: int
     lead = x.shape[:-1]
     x2 = x.reshape(-1, F)
     R = x2.shape[0]
-    nz = x2 != 0
+    nz = _nonzero(x2)
     bits = jnp.pad(nz, ((0, 0), (0, W * _WORD - F)))
     words = (bits.reshape(R, W, _WORD).astype(jnp.uint32)
              << jnp.arange(_WORD, dtype=jnp.uint32)).sum(

@@ -83,13 +83,6 @@ def _pad_to(x: jax.Array, mult: int, axis: int, fill):
     return jnp.pad(x, widths, constant_values=fill)
 
 
-def _feat_mult(interpret: bool) -> int:
-    """Feature-axis padding granule: 128 lanes on hardware; 8 in interpret
-    mode, where the kernel runs a single full-width feature block and
-    lane-padding a narrow F to 128 would inflate every round's traffic."""
-    return 8 if interpret else K.FEAT_BLOCK
-
-
 # ---------------------------------------------------------------------------
 # the edge schedule: destination-binned order + banded idle-skip bounds
 # ---------------------------------------------------------------------------
@@ -190,19 +183,11 @@ def schedule_edges(dst: jax.Array, mask: Optional[jax.Array], n_rows: int, *,
     only derives the banded bounds — for streams that are binned by
     construction, e.g. the sampled path's ``repeat(arange(R), K)`` seeds.
 
-    ``edge_tile`` defaults to the width the kernel dispatch on this backend
-    will use (``kernel.edge_tile``) — pass it explicitly only to study
-    other tilings.
+    ``edge_tile`` defaults to the kernel's ``EDGE_TILE`` — pass it
+    explicitly only to study other tilings.
     """
     if edge_tile is None:
-        interp = jax.default_backend() != "tpu"
-        edge_tile = K.edge_tile("add", interp)
-        # the schedule is op-independent, so its default width is only safe
-        # while every op dispatches the same tile; fail loudly if the cmp
-        # width is ever re-split from the add width (it was 32 before)
-        assert edge_tile == K.edge_tile("max", interp), (
-            "add/cmp edge tiles diverged — schedule_edges needs an explicit "
-            "edge_tile per op")
+        edge_tile = K.EDGE_TILE
     bins, n_blocks = _edge_bins(dst, mask, n_rows)
     iota = jnp.arange(dst.shape[0], dtype=jnp.int32)
     if assume_sorted:
@@ -231,7 +216,7 @@ def dense_skip_stats(dst: jax.Array, mask: Optional[jax.Array],
     same edge stream — the dead-row routing and tile padding reproduce
     exactly what ``gas_scatter_fused`` dispatches without a schedule, so
     benchmarks and tests count the grid the kernel actually runs."""
-    et = K.edge_tile("add", jax.default_backend() != "tpu")
+    et = K.EDGE_TILE
     R = ((n_rows + K.ROW_BLOCK - 1) // K.ROW_BLOCK) * K.ROW_BLOCK
     ok = (dst >= 0) & (dst < n_rows)
     if mask is not None:
@@ -295,8 +280,8 @@ def _gas_scatter_jit(dst: jax.Array, values: jax.Array, n_rows: int, *,
         return _gas_scatter_jit(dst, values[:, None], n_rows, op=op,
                                 interpret=interpret)[:, 0]
 
-    E, F = values.shape
-    et = K.edge_tile(op, interpret)
+    F = values.shape[1]
+    et = K.EDGE_TILE
     R = ((n_rows + K.ROW_BLOCK - 1) // K.ROW_BLOCK) * K.ROW_BLOCK
 
     # dead-row padding: invalid/padded edges target row R (outside all blocks)
@@ -306,7 +291,7 @@ def _gas_scatter_jit(dst: jax.Array, values: jax.Array, n_rows: int, *,
     fill = {"add": 0.0, "max": -jnp.inf, "min": jnp.inf}[op]
     valp = jnp.where(ok[:, None], values, fill)
     valp = _pad_to(valp, et, 0, fill)
-    valp = _pad_to(valp, _feat_mult(interpret), 1, fill)
+    valp = _pad_to(valp, K.FEAT_BLOCK, 1, fill)
 
     occ = occupancy_map(dstp, R // K.ROW_BLOCK, et)
     out = K.gas_scatter_pallas(dstp, valp, occ, R, op=op, interpret=interpret)
@@ -357,15 +342,15 @@ def _gas_scatter_fused_jit(dst: jax.Array, values: jax.Array,
                                       n_rows, op=op, schedule=schedule,
                                       interpret=interpret)[:, 0]
 
-    E, F = values.shape
-    et = K.edge_tile(op, interpret)
+    F = values.shape[1]
+    et = K.EDGE_TILE
     R = ((n_rows + K.ROW_BLOCK - 1) // K.ROW_BLOCK) * K.ROW_BLOCK
 
     ok = (dst >= 0) & (dst < n_rows)
     if mask is not None:
         ok = ok & mask
     dstp = _pad_to(jnp.where(ok, dst, R), et, 0, R)
-    valp = _pad_to(_pad_to(values, et, 0, 0), _feat_mult(interpret), 1, 0)
+    valp = _pad_to(_pad_to(values, et, 0, 0), K.FEAT_BLOCK, 1, 0)
     wp = None
     if op == "add" and weights is not None:
         wp = _pad_to(weights, et, 0, 0)
@@ -396,37 +381,32 @@ def _gas_scatter_fused_jit(dst: jax.Array, values: jax.Array,
             # no schedule or VJP changes — the backward pass re-derives it
             # from the fresh cotangent values.
             work = jnp.concatenate(
-                [work, _feat_liveness(valp, work[:, 1], et, interpret)],
+                [work, _feat_liveness(valp, work[:, 1])],
                 axis=1)
         out = K.gas_scatter_banded(work, dstp, valp, R, op=op,
                                    weights=wp, interpret=interpret)
     return out[:n_rows, :F]
 
 
-def _feat_liveness(valp: jax.Array, tiles: jax.Array, et: int,
-                   interpret: bool) -> jax.Array:
-    """(W, F//fb) int32: does work row w's edge tile have any nonzero value
-    in feature block f? ``valp`` is the tile- and feature-padded value
-    stream the kernel consumes."""
+def _feat_liveness(valp: jax.Array, tiles: jax.Array) -> jax.Array:
+    """(W, F//FEAT_BLOCK) int32: does work row w's edge tile have any
+    nonzero value in feature block f? ``valp`` is the tile- and
+    feature-padded value stream the kernel consumes."""
+    et, fb = K.EDGE_TILE, K.FEAT_BLOCK
     T, Fp = valp.shape[0] // et, valp.shape[1]
-    fb = Fp if interpret else K.FEAT_BLOCK
     tile_live = (valp.reshape(T, et, Fp // fb, fb) != 0).any(axis=(1, 3))
     return jnp.take(tile_live.astype(jnp.int32), tiles, axis=0)
 
 
-def feat_skip_stats(schedule: EdgeSchedule, values: jax.Array, *,
-                    interpret: bool | None = None):
+def feat_skip_stats(schedule: EdgeSchedule, values: jax.Array):
     """(live_rounds, band_rounds) of a scheduled add dispatch over these
     values — how many (row-block × edge-tile × feature-block) rounds the
     feature-skipping walk executes vs the banded walk without value
     occupancy (band rounds × feature blocks). The gap is the compressed-
     sparse win one level below the byte counters: rounds scale with the
     values' measured block density. Counted, not clocked."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    et = K.edge_tile("add", interpret)
-    valp = _pad_to(_pad_to(values, et, 0, 0), _feat_mult(interpret), 1, 0)
-    feat = _feat_liveness(valp, schedule.work[:, 1], et, interpret)
+    valp = _pad_to(_pad_to(values, K.EDGE_TILE, 0, 0), K.FEAT_BLOCK, 1, 0)
+    feat = _feat_liveness(valp, schedule.work[:, 1])
     live = schedule.work[:, 2] == 1
     return (int((feat * live[:, None].astype(jnp.int32)).sum()),
             int(live.sum()) * feat.shape[1])

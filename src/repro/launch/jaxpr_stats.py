@@ -35,18 +35,18 @@ import jax
 from repro.compat import COLLECTIVE_ALIASES, canonical_collective
 
 #: the cross-shard communication primitives of the CGTrans dataflows, by
-#: CANONICAL name — the jaxpr spellings drift across JAX versions (``psum``
-#: traces as ``psum2`` under some shard_map replication checkers,
-#: ``lax.psum_scatter`` lowers to a primitive named ``reduce_scatter``,
-#: ``ppermute`` to ``collective_permute``), so the version-sensitive alias
-#: table lives in ``repro.compat`` per the single-door rule and every count
-#: this module reports is folded onto the canonical key.
+#: CANONICAL name — the jaxpr spellings differ from the API names (``psum``
+#: inside shard_map traces as ``psum_invariant``, ``lax.psum_scatter`` as
+#: ``reduce_scatter``), so the alias table lives in ``repro.compat`` per the
+#: single-door rule and every count this module reports is folded onto the
+#: canonical key.
 COLLECTIVE_PRIMITIVES = tuple(COLLECTIVE_ALIASES)
 
 
 def canonicalize_collectives(counts: Counter) -> Counter:
     """Fold version-specific collective spellings onto their canonical names
-    (``psum2`` → ``psum``, ``reduce_scatter`` → ``psum_scatter``, …);
+    (``psum_invariant`` → ``psum``, ``reduce_scatter`` → ``psum_scatter``,
+    …);
     non-collective primitive names pass through unchanged."""
     out: Counter = Counter()
     for name, n in counts.items():

@@ -4,8 +4,8 @@
 importing this module touches no jax device state. Shapes per the assignment:
 (16, 16) = one v5e pod (256 chips), (2, 16, 16) = two pods over DCN.
 
-All mesh construction goes through ``repro.compat.make_mesh`` so the same
-code lowers on JAX 0.4.x (no ``axis_types=``) and current JAX alike.
+All mesh construction goes through ``repro.compat.make_mesh`` (the single
+door for version-sensitive JAX APIs).
 """
 
 from __future__ import annotations

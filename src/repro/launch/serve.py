@@ -164,6 +164,8 @@ def main(argv=None) -> int:
     ap.add_argument("--max-delay-ms", type=float, default=5.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     return _main_graph(args) if args.workload == "graph" else _main_lm(args)
 

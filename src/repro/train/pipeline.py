@@ -43,16 +43,21 @@ from repro.common.config import ModelConfig, TrainConfig
 from repro.compat import shard_map
 
 
-def make_sage_train_step(cfg, tc: TrainConfig, *, feats,
+def make_sage_train_step(cfg, tc: TrainConfig, *,
                          mesh: Optional[Mesh] = None,
                          relabel=None) -> Callable:
-    """(state, batch) → (state, metrics) for GraphSAGE + CGTrans training.
+    """(state, batch, feats) → (state, metrics) for GraphSAGE + CGTrans
+    training.
 
     ``cfg`` is a ``repro.core.gcn.GCNConfig`` — its ``dataflow``, ``impl``,
     ``request_chunk`` and ``scheduled`` fields select the transmission
     dataflow, the GAS backend, the request-stream chunking and the
-    idle-skip locality scheduling for every aggregation in the step. ``feats`` is the owner-sharded (P, part, F) feature table (the
-    storage tier); ``state`` is ``{"params", "opt", "step"}``.
+    idle-skip locality scheduling for every aggregation in the step.
+    ``feats`` is the owner-sharded (P, part, F) feature table (the storage
+    tier) and ``state`` is ``{"params", "opt", "step"}``. The table is an
+    ARGUMENT of the step, never closed over: ``jax.jit`` embeds a
+    closed-over array in the program as a constant, which at a real table
+    size is a program of hundreds of MB.
 
     ``impl="pallas"`` trains end-to-end: the FAST-GAS kernel carries custom
     VJPs (``repro.core.gas``) whose backward is itself in-SSD GAS work — a
@@ -69,7 +74,7 @@ def make_sage_train_step(cfg, tc: TrainConfig, *, feats,
     from repro.core.gcn import sage_loss
     from repro.optim import adamw_update
 
-    def train_step(state, batch):
+    def train_step(state, batch, feats):
         (loss, metrics), grads = jax.value_and_grad(
             lambda p: sage_loss(p, feats, batch, cfg, mesh=mesh,
                                 relabel=relabel),

@@ -460,10 +460,10 @@ def _train_parity_on_mesh(mesh):
         params = init_params(gcn_schema(cfg), jax.random.PRNGKey(0))
         state = {"params": params, "opt": adamw_init(params, tc),
                  "step": jnp.zeros((), jnp.int32)}
-        step = jax.jit(make_sage_train_step(cfg, tc, feats=feats, mesh=mesh))
+        step = jax.jit(make_sage_train_step(cfg, tc, mesh=mesh))
         losses, snaps = [], []
         for b in batches:
-            state, m = step(state, b)
+            state, m = step(state, b, feats)
             losses.append(float(m["total_loss"]))
             snaps.append(jax.tree.map(np.asarray, state["params"]))
         runs[impl] = (losses, snaps)
@@ -778,14 +778,14 @@ def case_distributed_sage_training():
              "step": jnp.zeros((), jnp.int32)}
     stream = GraphBatchStream(g, labels, n_parts=8, batch_per_part=16, k1=4, k2=4)
 
-    step = jax.jit(make_sage_train_step(cfg, tc, feats=feats, mesh=mesh))
+    step = jax.jit(make_sage_train_step(cfg, tc, mesh=mesh))
 
     losses = []
     for i, batch in zip(range(60), stream):
         b = {k: jnp.asarray(v) for k, v in batch.items()}
         b["mask1"] = b["mask1"].astype(bool)
         b["mask2"] = b["mask2"].astype(bool)
-        state, m = step(state, b)
+        state, m = step(state, b, feats)
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0] * 0.8, (losses[0], losses[-1])
     print(f"sage training ok: loss {losses[0]:.3f} -> {losses[-1]:.3f}")
@@ -999,9 +999,8 @@ def case_islandized_parity():
         p0 = init_params(gcn_schema(cfg_i), jax.random.PRNGKey(1))
         st = {"params": p0, "opt": adamw_init(p0, tc),
               "step": jnp.zeros((), jnp.int32)}
-        step = jax.jit(make_sage_train_step(cfg, tc, feats=t, mesh=mesh,
-                                            relabel=rl))
-        st, _m = step(st, batch)
+        step = jax.jit(make_sage_train_step(cfg, tc, mesh=mesh, relabel=rl))
+        st, _m = step(st, batch, t)
         snaps[name] = jax.tree.map(np.asarray, st["params"])
     for k in snaps["interval"]:
         exact(snaps["interval"][k], snaps["island"][k], ("train", k))

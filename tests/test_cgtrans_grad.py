@@ -366,10 +366,10 @@ def test_sage_train_step_pallas_three_steps():
         params = init_params(gcn_schema(cfg), jax.random.PRNGKey(0))
         state = {"params": params, "opt": adamw_init(params, tc),
                  "step": jnp.zeros((), jnp.int32)}
-        step = jax.jit(make_sage_train_step(cfg, tc, feats=feats, mesh=None))
+        step = jax.jit(make_sage_train_step(cfg, tc, mesh=None))
         losses, snaps = [], []
         for b in batches:
-            state, m = step(state, b)
+            state, m = step(state, b, feats)
             losses.append(float(m["total_loss"]))
             snaps.append(jax.tree.map(np.asarray, state["params"]))
         trajectories[impl] = (losses, snaps)

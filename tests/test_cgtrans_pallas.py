@@ -149,7 +149,7 @@ def test_property_occupancy_never_skips_live_tile(e, r, seed):
 
     rng = np.random.default_rng(seed)
     dst = rng.integers(-3, r + 3, e).astype(np.int32)   # incl. out-of-range
-    et = K.EDGE_TILE_ADD
+    et = K.EDGE_TILE
     R = ((r + K.ROW_BLOCK - 1) // K.ROW_BLOCK) * K.ROW_BLOCK
     ok = (dst >= 0) & (dst < r)
     dstp = np.where(ok, dst, R)

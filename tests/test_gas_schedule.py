@@ -87,7 +87,7 @@ def test_property_work_list_covers_live_rounds_exactly(e, r, seed):
     mask = rng.random(e) < 0.8
     sched = gas_ops.schedule_edges(jnp.asarray(dst), jnp.asarray(mask), r)
     perm = np.asarray(sched.perm)
-    et = K.edge_tile("add", True)
+    et = K.EDGE_TILE
     n_blocks = -(-r // K.ROW_BLOCK)
 
     live = mask & (dst >= 0) & (dst < r)

@@ -53,13 +53,13 @@ def test_1d_values(rng):
 
 def test_occupancy_is_idle_skip_safe(rng):
     """Rounds marked idle by the occupancy map truly have no matches."""
-    E = 4 * K.EDGE_TILE_ADD
+    E = 4 * K.EDGE_TILE
     R = 4 * K.ROW_BLOCK
     dst = rng.integers(0, R, E).astype(np.int32)
-    dst[:K.EDGE_TILE_ADD] = 0  # first tile only touches row block 0
+    dst[:K.EDGE_TILE] = 0  # first tile only touches row block 0
     occ = np.asarray(occupancy_map(jnp.asarray(dst), R // K.ROW_BLOCK,
-                                   K.EDGE_TILE_ADD))
-    tiles = dst.reshape(-1, K.EDGE_TILE_ADD) // K.ROW_BLOCK
+                                   K.EDGE_TILE))
+    tiles = dst.reshape(-1, K.EDGE_TILE) // K.ROW_BLOCK
     for r in range(occ.shape[0]):
         for t in range(occ.shape[1]):
             if not occ[r, t]:
