@@ -1,0 +1,3 @@
+"""Per-layer metric ``gas_roofline.infer``: see ``yard.readers.gas_roofline``."""
+
+from yard.readers import gas_roofline as read  # noqa: F401

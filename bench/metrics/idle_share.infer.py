@@ -1,0 +1,3 @@
+"""Per-layer metric ``idle_share.infer``: see ``yard.readers.idle_share``."""
+
+from yard.readers import idle_share as read  # noqa: F401
