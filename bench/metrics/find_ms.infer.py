@@ -1,0 +1,3 @@
+"""Per-layer metric ``find_ms.infer``: see ``yard.scopes.find_ms``."""
+
+from yard.scopes import find_ms as read  # noqa: F401

@@ -242,7 +242,8 @@ def _find(table, ids, *, impl: str, sparse_cap: Optional[int] = None):
     if sparse_cap is None:
         return gas.gas_gather(table, ids, impl=impl)
     gas._tick("find")
-    return _sparse_gather(table.shape[0], sparse_cap, impl)(table, ids)
+    with jax.named_scope("gas.find"):
+        return _sparse_gather(table.shape[0], sparse_cap, impl)(table, ids)
 
 
 def _sparse_ship(x, wire: str, capacity: int):
@@ -317,7 +318,8 @@ def _permuted(sched, *arrays):
     """Apply an edge schedule's permutation to per-edge arrays. Autodiff
     transposes the ``take`` into the exact un-permuting scatter, so
     cotangents to weights (and values) return in original edge order."""
-    return tuple(jnp.take(a, sched.perm, axis=0) for a in arrays)
+    with jax.named_scope("gas.schedule"):
+        return tuple(jnp.take(a, sched.perm, axis=0) for a in arrays)
 
 
 def is_sharded(mesh: Optional[Mesh]) -> bool:
@@ -352,10 +354,11 @@ def apply_edge_schedule(schedule, *edge_arrays):
     meaningful for per-shard schedules (sharded-mesh layout); local src
     ids, weights and masks all permute shard-locally.
     """
-    return tuple(
-        jax.vmap(lambda a, p: jnp.take(a, p, axis=0), in_axes=(0, 0))(
-            a, schedule.perm)
-        for a in edge_arrays)
+    with jax.named_scope("gas.schedule"):
+        return tuple(
+            jax.vmap(lambda a, p: jnp.take(a, p, axis=0), in_axes=(0, 0))(
+                a, schedule.perm)
+            for a in edge_arrays)
 
 
 # ---------------------------------------------------------------------------
@@ -648,20 +651,23 @@ def scan_request_chunks(body, nbrs2d, mask2d, chunk: int):
     are sliced off. Chunking partitions rows (never a row's K entries), so
     the result is bit-exact with one full-block ``body`` call. ``body`` maps
     an (chunk, K) id/mask pair to (chunk, F) output rows. Shared with the
-    chunked embedding lookup (``repro.models.embedding``).
+    chunked embedding lookup (``repro.models.embedding``). The scope spans
+    the scan itself, so its per-chunk slicing and output writes are named
+    with the body's work.
     """
     R = nbrs2d.shape[0]
     chunk = max(1, min(chunk, R))
-    nb = _pad_rows(nbrs2d, chunk, 0)
-    mk = _pad_rows(mask2d, chunk, False)
-    steps = nb.shape[0] // chunk
 
     def step(_, inp):
         return None, body(*inp)
 
-    _, outs = lax.scan(step, None,
-                       (nb.reshape(steps, chunk, -1), mk.reshape(steps, chunk, -1)))
-    return outs.reshape(steps * chunk, -1)[:R]
+    with jax.named_scope("cgtrans.chunk"):
+        nb = _pad_rows(nbrs2d, chunk, 0)
+        mk = _pad_rows(mask2d, chunk, False)
+        steps = nb.shape[0] // chunk
+        _, outs = lax.scan(step, None, (nb.reshape(steps, chunk, -1),
+                                        mk.reshape(steps, chunk, -1)))
+        return outs.reshape(steps * chunk, -1)[:R]
 
 
 class SegmentDescriptor(NamedTuple):

@@ -125,8 +125,9 @@ def schedule_edges(dst: jax.Array, mask: Optional[jax.Array], n_rows: int, *,
     for streams binned by construction (e.g. sampled-path seed rows).
     """
     from repro.kernels.gas_scatter import ops as gas_ops
-    return gas_ops.schedule_edges(dst, mask, n_rows,
-                                  assume_sorted=assume_sorted)
+    with jax.named_scope("gas.schedule"):
+        return gas_ops.schedule_edges(dst, mask, n_rows,
+                                      assume_sorted=assume_sorted)
 
 
 def gas_scatter(dst: jax.Array, values: jax.Array, n_rows: int, *,
@@ -192,16 +193,17 @@ def gas_gather(table: jax.Array, ids: jax.Array, *, impl: str = "xla") -> jax.Ar
     kernel, so the reverse pass of a dataflow stays in the in-SSD regime.
     """
     _tick("find")
-    if impl == "pallas":
-        if table.ndim != 2:
-            # a silent jnp.take fallback here would hand the backward to an
-            # XLA scatter — the exact regression the grad tier forbids
-            raise NotImplementedError(
-                f"gas_gather(impl='pallas') routes its VJP through the "
-                f"FAST-GAS kernel and requires a 2-D (rows, F) table; got "
-                f"ndim={table.ndim}. Use impl='xla' for other ranks.")
-        return _gather_pallas(table.shape[0])(table, ids)
-    return jnp.take(table, ids, axis=0)
+    if impl == "pallas" and table.ndim != 2:
+        # a silent jnp.take fallback here would hand the backward to an
+        # XLA scatter — the exact regression the grad tier forbids
+        raise NotImplementedError(
+            f"gas_gather(impl='pallas') routes its VJP through the "
+            f"FAST-GAS kernel and requires a 2-D (rows, F) table; got "
+            f"ndim={table.ndim}. Use impl='xla' for other ranks.")
+    with jax.named_scope("gas.find"):
+        if impl == "pallas":
+            return _gather_pallas(table.shape[0])(table, ids)
+        return jnp.take(table, ids, axis=0)
 
 
 def gas_match(keys: jax.Array, queries: jax.Array) -> jax.Array:
@@ -347,16 +349,20 @@ def gas_scatter_weighted(dst: jax.Array, src_vals: jax.Array, weights: jax.Array
     backward (tie counts) and cotangents un-permute through the transpose
     of the caller's ``take``.
     """
-    if impl == "pallas":
-        if op == "or":
-            # flat almost everywhere (the oracle differentiates to exact
-            # zeros through its int cast): stop the gradients instead of
-            # paying custom-VJP residuals for an all-zero backward
-            return _scatter_weighted_impl(
-                dst, jax.lax.stop_gradient(src_vals),
-                jax.lax.stop_gradient(weights), mask, n_rows, op, impl,
-                schedule)
-        return _scatter_weighted_pallas(n_rows, op)(dst, src_vals, weights,
-                                                    mask, schedule)
-    return _scatter_weighted_impl(dst, src_vals, weights, mask, n_rows, op,
-                                  impl, schedule)
+    # the scope encloses the custom-VJP call, so the backward carries it
+    # too; the gathers' backward scatters call ``_scatter_weighted_impl``
+    # directly and stay under their own ``gas.find``
+    with jax.named_scope("gas.reduce"):
+        if impl == "pallas":
+            if op == "or":
+                # flat almost everywhere (the oracle differentiates to exact
+                # zeros through its int cast): stop the gradients instead of
+                # paying custom-VJP residuals for an all-zero backward
+                return _scatter_weighted_impl(
+                    dst, jax.lax.stop_gradient(src_vals),
+                    jax.lax.stop_gradient(weights), mask, n_rows, op, impl,
+                    schedule)
+            return _scatter_weighted_pallas(n_rows, op)(
+                dst, src_vals, weights, mask, schedule)
+        return _scatter_weighted_impl(dst, src_vals, weights, mask, n_rows,
+                                      op, impl, schedule)

@@ -158,13 +158,17 @@ def gcn_forward_full(params, feats, src_local, dst_global, weights, mask,
             # dense activations whose measured capacity would be F anyway
             features=cfg.features if i == 0 else "dense",
             sparse_capacity=cfg.sparse_capacity if i == 0 else None)
-        if cfg.aggregate in ("max", "min"):
-            # vertices with no in-edges hold the ±inf identity; mask before
-            # the combine so neither the forward nor the cotangent meets inf
-            agg = jnp.where(jnp.isfinite(agg), agg, 0.0)
-        h = jnp.concatenate([h, agg], axis=-1)
-        h = jax.nn.relu(jnp.einsum("pvf,fh->pvh", h, params[f"w{i}"]) + params[f"b{i}"])
-    out = jnp.einsum("pvh,hc->pvc", h, params["w_out"]) + params["b_out"]
+        with jax.named_scope("gcn.combine"):
+            if cfg.aggregate in ("max", "min"):
+                # vertices with no in-edges hold the ±inf identity; mask
+                # before the combine so neither the forward nor the
+                # cotangent meets inf
+                agg = jnp.where(jnp.isfinite(agg), agg, 0.0)
+            h = jnp.concatenate([h, agg], axis=-1)
+            h = jax.nn.relu(jnp.einsum("pvf,fh->pvh", h, params[f"w{i}"])
+                            + params[f"b{i}"])
+    with jax.named_scope("gcn.combine"):
+        out = jnp.einsum("pvh,hc->pvc", h, params["w_out"]) + params["b_out"]
     if relabel is not None:
         # un-permute: islandized row relabel[v] holds original vertex v.
         # Interval mode places vertex v at flat row v exactly (owner = v //
@@ -265,23 +269,27 @@ def sage_forward(params, feats, batch, cfg: GCNConfig, *,
             wire=cfg.wire, features=cfg.features,
             sparse_capacity=cfg.sparse_capacity)
 
-    h1 = jnp.concatenate([x_self, x_agg], axis=-1)
-    h1 = jax.nn.relu(jnp.einsum("pbf,fh->pbh", h1, params["w0"]) + params["b0"])
-    h1 = h1.reshape(Pn, B, 1 + K1, -1)
+    with jax.named_scope("gcn.combine"):
+        h1 = jnp.concatenate([x_self, x_agg], axis=-1)
+        h1 = jax.nn.relu(jnp.einsum("pbf,fh->pbh", h1, params["w0"])
+                         + params["b0"])
+        h1 = h1.reshape(Pn, B, 1 + K1, -1)
 
-    # local step: aggregate 1-hop h1 per seed.
-    m1 = batch["mask1"][..., None].astype(h1.dtype)
-    agg1 = (h1[:, :, 1:] * m1).sum(2) / jnp.maximum(m1.sum(2), 1.0)
-    h2 = jnp.concatenate([h1[:, :, 0], agg1], axis=-1)
-    h2 = jax.nn.relu(jnp.einsum("pbf,fh->pbh", h2, params["w1"]) + params["b1"])
-    return jnp.einsum("pbh,hc->pbc", h2, params["w_out"]) + params["b_out"]
+        # local step: aggregate 1-hop h1 per seed.
+        m1 = batch["mask1"][..., None].astype(h1.dtype)
+        agg1 = (h1[:, :, 1:] * m1).sum(2) / jnp.maximum(m1.sum(2), 1.0)
+        h2 = jnp.concatenate([h1[:, :, 0], agg1], axis=-1)
+        h2 = jax.nn.relu(jnp.einsum("pbf,fh->pbh", h2, params["w1"])
+                         + params["b1"])
+        return jnp.einsum("pbh,hc->pbc", h2, params["w_out"]) + params["b_out"]
 
 
 def sage_loss(params, feats, batch, cfg: GCNConfig, *,
               mesh: Optional[Mesh] = None, relabel=None):
     logits = sage_forward(params, feats, batch, cfg, mesh=mesh, relabel=relabel)
     labels = batch["labels"]                  # (P, B)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    acc = (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
-    return nll.mean(), {"loss": nll.mean(), "acc": acc.mean()}
+    with jax.named_scope("gcn.combine"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        acc = (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
+        return nll.mean(), {"loss": nll.mean(), "acc": acc.mean()}

@@ -18,6 +18,7 @@ import os
 from typing import Dict, Iterator, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.graph.structure import COOGraph
 
@@ -118,21 +119,26 @@ class GraphBatchStream:
         return nbrs, mask
 
     def batch_at(self, step: int) -> Dict[str, np.ndarray]:
-        rng = np.random.default_rng(np.random.SeedSequence([self.seed, step]))
-        P, B = self.n_parts, self.batch_per_part
-        seeds = rng.integers(0, self.graph.n_vertices, (P, B)).astype(np.int32)
-        flat = seeds.reshape(-1)
-        n1, m1 = self._sample(rng, flat, self.k1)
-        lay1 = np.concatenate([flat[:, None], n1], axis=1).reshape(-1)
-        n2, m2 = self._sample(rng, lay1, self.k2)
-        return {
-            "seeds": seeds,
-            "nbrs1": n1.reshape(P, B, self.k1),
-            "mask1": m1.reshape(P, B, self.k1),
-            "nbrs2": n2.reshape(P, B * (1 + self.k1), self.k2),
-            "mask2": m2.reshape(P, B * (1 + self.k1), self.k2),
-            "labels": self.labels[seeds].astype(np.int32),
-        }
+        """The minibatch of ``step``, drawn inside the host span
+        ``repro.data.sample``."""
+        with TraceAnnotation("repro.data.sample"):
+            rng = np.random.default_rng(
+                np.random.SeedSequence([self.seed, step]))
+            P, B = self.n_parts, self.batch_per_part
+            seeds = rng.integers(0, self.graph.n_vertices,
+                                 (P, B)).astype(np.int32)
+            flat = seeds.reshape(-1)
+            n1, m1 = self._sample(rng, flat, self.k1)
+            lay1 = np.concatenate([flat[:, None], n1], axis=1).reshape(-1)
+            n2, m2 = self._sample(rng, lay1, self.k2)
+            return {
+                "seeds": seeds,
+                "nbrs1": n1.reshape(P, B, self.k1),
+                "mask1": m1.reshape(P, B, self.k1),
+                "nbrs2": n2.reshape(P, B * (1 + self.k1), self.k2),
+                "mask2": m2.reshape(P, B * (1 + self.k1), self.k2),
+                "labels": self.labels[seeds].astype(np.int32),
+            }
 
     def __iter__(self):
         step = 0

@@ -14,6 +14,7 @@ from typing import Callable, Dict, Iterable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import CheckpointManager
 from repro.runtime import PreemptionGuard, StepMonitor
@@ -32,7 +33,12 @@ def train_loop(
     guard: Optional[PreemptionGuard] = None,
     log_fn: Callable[[str], None] = print,
 ):
-    """Runs to total_steps (resuming if a checkpoint exists). Returns state."""
+    """Runs to total_steps (resuming if a checkpoint exists). Returns
+    (state, number of steps completed, the restored ones included).
+
+    Each step's ``step_fn`` call and its wait run inside the host span
+    ``repro.train.step``, which a profiler session records (a no-op
+    otherwise)."""
     start_step = 0
     if ckpt is not None and ckpt.latest_step() is not None:
         state, start_step = ckpt.restore(state)
@@ -44,13 +50,15 @@ def train_loop(
     for _ in range(start_step):
         next(it)
 
-    step = start_step
+    done = start_step
     for step in range(start_step, total_steps):
         batch = next(it)
         t0 = time.perf_counter()
-        state, metrics = step_fn(state, batch)
-        jax.block_until_ready(metrics["total_loss"] if "total_loss" in metrics
-                              else jax.tree.leaves(metrics)[0])
+        with TraceAnnotation("repro.train.step"):
+            state, metrics = step_fn(state, batch)
+            jax.block_until_ready(metrics["total_loss"]
+                                  if "total_loss" in metrics
+                                  else jax.tree.leaves(metrics)[0])
         dt = time.perf_counter() - t0
         straggler = monitor.record(step, dt)
         if straggler:
@@ -70,4 +78,4 @@ def train_loop(
             return state, done
     if ckpt is not None:
         ckpt.wait()
-    return state, step + 1
+    return state, done

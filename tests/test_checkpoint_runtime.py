@@ -124,6 +124,24 @@ def test_train_loop_resume_exactness(tmp_path):
                                np.asarray(full["params"]["w"]), atol=1e-6)
 
 
+def test_train_loop_resumed_at_its_end_returns_steps_done(tmp_path):
+    """Resumed at or past total_steps, the loop runs no step and returns the
+    number of steps completed, not one more."""
+    ck = CheckpointManager(str(tmp_path))
+    st0 = {"params": {"w": jnp.zeros(2)}, "step": jnp.asarray(0, jnp.int32)}
+    _, n = train_loop(step_fn=_quadratic_step, state=st0, batches=_Batches(),
+                      total_steps=4, ckpt=ck, ckpt_every=2, log_every=0)
+    assert n == 4
+
+    def never(state, batch):
+        raise AssertionError("no step is left to run")
+
+    for total in (4, 3):
+        _, n = train_loop(step_fn=never, state=st0, batches=_Batches(),
+                          total_steps=total, ckpt=ck, log_every=0)
+        assert n == 4
+
+
 def test_train_loop_preemption_checkpoints(tmp_path):
     ck = CheckpointManager(str(tmp_path))
     guard = PreemptionGuard(install=False)

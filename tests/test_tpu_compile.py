@@ -109,3 +109,33 @@ def test_prefetch_over_smem_budget_is_refused():
     with pytest.raises(ValueError, match="SMEM"):
         ops.gas_scatter(jnp.zeros((E,), jnp.int32),
                         jnp.zeros((E, 1), jnp.float32), n_rows)
+
+
+def test_scoped_kernel_keeps_its_wrapper_name(one_chip, monkeypatch):
+    """Under the program's ``gas.reduce`` scope the kernel's HLO op is still
+    named after its jitted wrapper, the name a trace reduction finds the
+    kernel by, and carries the scope in its ``metadata.op_name``."""
+    import re
+
+    from repro.core import gas
+    # the wrappers choose interpret mode off the TPU; compile the chip's path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    E, n_rows = DISPATCHES["chunk"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(dst, vals, w, mask, sched):
+        return gas.gas_scatter_weighted(dst, vals, w, mask, n_rows,
+                                        impl="pallas", schedule=sched)
+
+    args = (sds((E,), jnp.int32), sds((E, 602), jnp.float32),
+            sds((E,), jnp.float32), sds((E,), jnp.bool_),
+            _schedule_shapes(E, n_rows, sds))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"'
+                       r'[^\n]*op_name="([^"]*)"', text)
+    assert calls
+    for name, stack in calls:
+        assert name.startswith("gas_scatter_banded"), name
+        assert "gas.reduce" in stack.split("/"), stack
