@@ -3,7 +3,8 @@
 * ``StepMonitor`` — EWMA step-time tracker; flags straggler steps (z-score
   over a robust MAD estimate). In a multi-host deployment each host runs one
   and the controller compares `snapshot()`s; slow hosts get drained (the hook
-  is ``on_straggler``).
+  is ``on_straggler``). It also counts the training loop's lookahead pulls
+  and how many of them the device's step hid.
 * ``Heartbeat``   — liveness file for an external supervisor (touch every K
   seconds; supervisor restarts the job if stale).
 * ``PreemptionGuard`` — converts SIGTERM into a cooperative "checkpoint and
@@ -29,6 +30,8 @@ class StepMonitor:
         self.on_straggler = on_straggler
         self.flagged = 0
         self.steps = 0
+        self.lookahead_pulls = 0
+        self.lookahead_hidden = 0
         self._ewma: Optional[float] = None
 
     def record(self, step: int, seconds: float) -> bool:
@@ -53,9 +56,19 @@ class StepMonitor:
         self._ewma = seconds if self._ewma is None else a * seconds + (1 - a) * self._ewma
         return is_bad
 
+    def record_lookahead(self, hidden: bool) -> None:
+        """One batch pulled while a step was in flight; ``hidden`` if the
+        step was still running when the pull finished."""
+        self.lookahead_pulls += 1
+        self.lookahead_hidden += bool(hidden)
+
     def snapshot(self) -> Dict[str, float]:
+        pulls = self.lookahead_pulls
         return {"ewma_s": self._ewma or 0.0, "flagged": self.flagged,
-                "steps": self.steps}
+                "steps": self.steps, "lookahead_pulls": pulls,
+                "lookahead_hidden": self.lookahead_hidden,
+                "lookahead_hidden_share":
+                    self.lookahead_hidden / pulls if pulls else 0.0}
 
 
 class Heartbeat:

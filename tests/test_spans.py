@@ -4,7 +4,8 @@ Device work is named with ``jax.named_scope`` (it lands in each HLO op's
 ``metadata.op_name``): ``gas.find``, ``gas.schedule``, ``gas.reduce``,
 ``cgtrans.chunk`` and ``gcn.combine``. Host work is named with
 ``jax.profiler.TraceAnnotation``: ``repro.data.sample`` around a minibatch
-draw and ``repro.train.step`` around a training step and its wait. A trace
+draw, ``repro.train.step`` around a training step, the next batch's draw and
+the wait, and ``repro.train.wait`` around the wait alone. A trace
 reduction attributes each op to the innermost of the scopes in its name
 stack, so the three GAS scopes must never nest inside one another.
 """
@@ -113,9 +114,11 @@ def test_gas_scopes_never_nest(programs, program):
 
 
 def _host_events(path: str, names):
+    """(name, start_ns, end_ns) of each event named in ``names``, by start."""
     pd = jax.profiler.ProfileData.from_file(path)
-    return [ev.name for plane in pd.planes for line in plane.lines
-            for ev in line.events if ev.name in names]
+    return sorted(((ev.name, ev.start_ns, ev.end_ns) for plane in pd.planes
+                   for line in plane.lines for ev in line.events
+                   if ev.name in names), key=lambda e: e[1])
 
 
 def test_host_spans_one_per_step(train, tmp_path):
@@ -130,6 +133,17 @@ def test_host_spans_one_per_step(train, tmp_path):
         jax.profiler.stop_trace()
     assert done == 2
     path = next(tmp_path.rglob("*.xplane.pb"))
-    names = _host_events(str(path), {"repro.data.sample", "repro.train.step"})
-    assert sorted(names) == (["repro.data.sample"] * 2
-                             + ["repro.train.step"] * 2)
+    events = _host_events(str(path), {"repro.data.sample", "repro.train.step",
+                                      "repro.train.wait"})
+    assert sorted(name for name, _, _ in events) == (
+        ["repro.data.sample"] * 2 + ["repro.train.step"] * 2
+        + ["repro.train.wait"] * 2)
+    samples, steps, waits = ([e[1:] for e in events if e[0] == name]
+                             for name in ("repro.data.sample",
+                                          "repro.train.step",
+                                          "repro.train.wait"))
+    # the second batch is pulled while the first step runs, before its wait
+    assert steps[0][0] <= samples[1][0] <= samples[1][1] <= waits[0][0]
+    assert waits[0][1] <= steps[0][1]
+    # the first batch is pulled before any step
+    assert samples[0][1] <= steps[0][0]
