@@ -101,6 +101,17 @@ from repro.core import wire as wirefmt
 
 AXIS = "data"  # the storage-tier axis
 
+# The exchange's name in the trace: every cross-chip collective of a sharded
+# body runs inside this scope (a transpose carries its forward's name), so a
+# trace reduction can read the exchange apart from the work around it. It is
+# not one of the scopes the per-scope readers attribute to, so their readings
+# still put the exchange's ops under the scope that encloses it.
+EXCHANGE_SCOPE = "cgtrans.exchange"
+
+
+def _exchange():
+    return jax.named_scope(EXCHANGE_SCOPE)
+
 
 # ---------------------------------------------------------------------------
 # the compressed wire (ROADMAP "make the C in CGTrans real"): the codecs live
@@ -116,11 +127,13 @@ def _wire_identity(op: gas.Op) -> float:
 
 
 def _wired_a2a(x, wire: str, identity: float, n_exact: int):
-    enc = wirefmt.encode_payload(x, wire, identity=identity, n_exact=n_exact)
-    parts = lax.all_to_all(enc, AXIS, split_axis=0, concat_axis=0,
-                           tiled=False)
-    return wirefmt.decode_payload(parts, wire, identity=identity,
-                                  n_exact=n_exact, out_dtype=x.dtype)
+    with _exchange():
+        enc = wirefmt.encode_payload(x, wire, identity=identity,
+                                     n_exact=n_exact)
+        parts = lax.all_to_all(enc, AXIS, split_axis=0, concat_axis=0,
+                               tiled=False)
+        return wirefmt.decode_payload(parts, wire, identity=identity,
+                                      n_exact=n_exact, out_dtype=x.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
@@ -251,28 +264,29 @@ def _sparse_ship(x, wire: str, capacity: int):
     ``all_to_all``, decode on arrival (f32 math under a narrow wire). The
     bitmap always travels as exact bitcast lanes — int16×2 / int8×4 per
     word — so only the nonzero VALUES ever quantize."""
-    F = x.shape[-1]
-    W = sparsefmt.bitmap_words(F)
-    packed, bitmap = sparsefmt.encode_rows(x, capacity)
-    if wire == "f32":
-        payload = jnp.concatenate(
-            [packed, lax.bitcast_convert_type(bitmap, x.dtype)], axis=-1)
-        parts = lax.all_to_all(payload, AXIS, split_axis=0, concat_axis=0,
-                               tiled=False)
-        pk = parts[..., :capacity]
-        bm = lax.bitcast_convert_type(parts[..., capacity:], jnp.int32)
-        return sparsefmt.decode_rows(pk, bm, F)
-    enc = wirefmt.encode_payload(packed.astype(jnp.float32), wire)
-    bits16 = lax.bitcast_convert_type(
-        bitmap, enc.dtype).reshape(*bitmap.shape[:-1], -1)
-    nb = bits16.shape[-1]
-    parts = lax.all_to_all(jnp.concatenate([enc, bits16], axis=-1), AXIS,
-                           split_axis=0, concat_axis=0, tiled=False)
-    pk = wirefmt.decode_payload(parts[..., :parts.shape[-1] - nb], wire)
-    bm = lax.bitcast_convert_type(
-        parts[..., parts.shape[-1] - nb:].reshape(
-            *parts.shape[:-1], W, nb // W), jnp.int32)
-    return sparsefmt.decode_rows(pk, bm, F).astype(x.dtype)
+    with _exchange():
+        F = x.shape[-1]
+        W = sparsefmt.bitmap_words(F)
+        packed, bitmap = sparsefmt.encode_rows(x, capacity)
+        if wire == "f32":
+            payload = jnp.concatenate(
+                [packed, lax.bitcast_convert_type(bitmap, x.dtype)], axis=-1)
+            parts = lax.all_to_all(payload, AXIS, split_axis=0, concat_axis=0,
+                                   tiled=False)
+            pk = parts[..., :capacity]
+            bm = lax.bitcast_convert_type(parts[..., capacity:], jnp.int32)
+            return sparsefmt.decode_rows(pk, bm, F)
+        enc = wirefmt.encode_payload(packed.astype(jnp.float32), wire)
+        bits16 = lax.bitcast_convert_type(
+            bitmap, enc.dtype).reshape(*bitmap.shape[:-1], -1)
+        nb = bits16.shape[-1]
+        parts = lax.all_to_all(jnp.concatenate([enc, bits16], axis=-1), AXIS,
+                               split_axis=0, concat_axis=0, tiled=False)
+        pk = wirefmt.decode_payload(parts[..., :parts.shape[-1] - nb], wire)
+        bm = lax.bitcast_convert_type(
+            parts[..., parts.shape[-1] - nb:].reshape(
+                *parts.shape[:-1], W, nb // W), jnp.int32)
+        return sparsefmt.decode_rows(pk, bm, F).astype(x.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
@@ -468,8 +482,9 @@ def aggregate_edges(
             # compressed transmission: reduce-scatter the (V, F) partials so
             # each shard receives exactly its owned interval, aggregated.
             if op == "add" and wire == "f32":
-                out = psum_scatter(partial.reshape(n, part, F), AXIS,
-                                   scatter_dimension=0)
+                with _exchange():
+                    out = psum_scatter(partial.reshape(n, part, F), AXIS,
+                                       scatter_dimension=0)
             elif op == "add":
                 # a narrow wire cannot ride psum_scatter (it would SUM the
                 # quantized codes on the wire — int8 codes from different
@@ -487,10 +502,12 @@ def aggregate_edges(
                 # differentiates this flow.) or-partials are ≥ 0, so max
                 # realizes boolean-or.
                 block = partial.reshape(n, part, F)
-                parts = (lax.all_to_all(block, AXIS, split_axis=0,
-                                        concat_axis=0, tiled=False)
-                         if wire == "f32" else
-                         _wire_all_to_all(block, wire, _wire_identity(op)))
+                if wire == "f32":
+                    with _exchange():
+                        parts = lax.all_to_all(block, AXIS, split_axis=0,
+                                               concat_axis=0, tiled=False)
+                else:
+                    parts = _wire_all_to_all(block, wire, _wire_identity(op))
                 out = parts.min(0) if op == "min" else parts.max(0)
             return out[None]
 
@@ -514,9 +531,10 @@ def aggregate_edges(
             if op == "add":
                 raw = raw * w[0][:, None].astype(raw.dtype)
             raw = jnp.where(m[0][:, None], raw, 0)
-            all_raw = lax.all_gather(raw, AXIS)          # (n, E, F) — E·F·n bytes
-            all_dst = lax.all_gather(d[0], AXIS)
-            all_m = lax.all_gather(m[0], AXIS)
+            with _exchange():
+                all_raw = lax.all_gather(raw, AXIS)      # (n, E, F) — E·F·n bytes
+                all_dst = lax.all_gather(d[0], AXIS)
+                all_m = lax.all_gather(m[0], AXIS)
             # destination side ("the accelerator"): keep only owned interval
             lo = lax.axis_index(AXIS) * part
             rel = all_dst.reshape(-1) - lo
@@ -908,11 +926,12 @@ def aggregate_multi(
             # On a narrow wire the stream ships as int16 first-order deltas
             # (half the bytes) whenever the vertex range statically fits —
             # the cumsum decode restores every id, -1 dead codes included.
-            if wire != "f32" and wirefmt.delta_ids_fit(n * part):
-                ids = wirefmt.delta_decode_ids(
-                    lax.all_gather(wirefmt.delta_encode_ids(flat), AXIS))
-            else:
-                ids = lax.all_gather(flat, AXIS)         # (n, N)
+            with _exchange():
+                if wire != "f32" and wirefmt.delta_ids_fit(n * part):
+                    ids = wirefmt.delta_decode_ids(
+                        lax.all_gather(wirefmt.delta_encode_ids(flat), AXIS))
+                else:
+                    ids = lax.all_gather(flat, AXIS)     # (n, N)
             rel = ids - lo                               # dead ids stay < 0
 
             if dataflow == "cgtrans":
@@ -938,8 +957,9 @@ def aggregate_multi(
                     payload = jnp.concatenate([payload, cnt[..., None]],
                                               axis=-1)
                 if wire == "f32":
-                    parts = lax.all_to_all(payload, AXIS, split_axis=0,
-                                           concat_axis=0, tiled=False)
+                    with _exchange():
+                        parts = lax.all_to_all(payload, AXIS, split_axis=0,
+                                               concat_axis=0, tiled=False)
                 else:
                     # quantize the shipment; the add path's count column is
                     # an "exact" column (int8 bitcasts it; bf16 carries
@@ -972,10 +992,12 @@ def aggregate_multi(
             else:
                 # sub-32-bit tables (bf16 serving) keep the dense ship: an
                 # int32 bitmap has no 16-bit bitcast lane to ride in
-                raw = lax.all_to_all(rows, AXIS, split_axis=0,
-                                     concat_axis=0, tiled=False)  # (n, N, F)
-            okk = lax.all_to_all(own[..., None], AXIS, split_axis=0,
-                                 concat_axis=0, tiled=False)[..., 0]
+                with _exchange():
+                    raw = lax.all_to_all(rows, AXIS, split_axis=0,
+                                         concat_axis=0, tiled=False)
+            with _exchange():
+                okk = lax.all_to_all(own[..., None], AXIS, split_axis=0,
+                                     concat_axis=0, tiled=False)[..., 0]
             outs, off = [], 0
             for r, k in shapes:
                 sl = slice(off, off + r * k)

@@ -15,16 +15,18 @@ import pytest
 _HERE = os.path.dirname(__file__)
 
 
-def run_distributed_case(case: str, timeout: int = 480) -> str:
-    """Run one tests/distributed_cases.py case in an 8-fake-device
-    subprocess (the main pytest process stays on the 1-device topology) and
+def run_distributed_case(case: str, timeout: int = 480,
+                         devices: int = 8) -> str:
+    """Run one tests/distributed_cases.py case in a subprocess with
+    ``devices`` fake CPU devices (the main pytest process stays on the
+    1-device topology) and
     return its stdout; pytest.fail with the child's output on any failure —
     an import/compat break in the subprocess must read as itself, not as
     ``assert 1 == 0`` around a CompletedProcess repr."""
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(_HERE, "..", "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     cmd = [sys.executable, os.path.join(_HERE, "distributed_cases.py"), case]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,

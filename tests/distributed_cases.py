@@ -1234,6 +1234,202 @@ def res_cap_fits(sfeats):
                                  sfeats.shape[-1])
 
 
+def _plain_sage_loss(params, table, batch):
+    """2-layer concat GraphSAGE in plain ``jax.numpy`` on the whole (V, F)
+    table: each layer-1 vertex takes its own row and the mean of its valid
+    2-hop rows, each seed its own hidden row and the mean of its valid
+    1-hop hidden rows; mean NLL over every seed of every chip."""
+    import jax
+    import jax.numpy as jnp
+
+    def mean(x, mask):                    # (..., K, D), (..., K) -> (..., D)
+        m = mask[..., None].astype(x.dtype)
+        return (x * m).sum(-2) / jnp.maximum(m.sum(-2), 1)
+
+    def dense(x, w, b):
+        return jnp.einsum("...i,io->...o", x, w) + b
+
+    n, B = batch["seeds"].shape
+    K1 = batch["nbrs1"].shape[-1]
+    ids1 = jnp.concatenate([batch["seeds"][..., None], batch["nbrs1"]], -1)
+    x_self = table[ids1]                                  # (n, B, 1+K1, F)
+    x_agg = mean(table[batch["nbrs2"]], batch["mask2"]).reshape(
+        n, B, 1 + K1, -1)
+    h1 = jax.nn.relu(dense(jnp.concatenate([x_self, x_agg], -1),
+                           params["w0"], params["b0"]))
+    h2 = jax.nn.relu(dense(jnp.concatenate(
+        [h1[:, :, 0], mean(h1[:, :, 1:], batch["mask1"])], -1),
+        params["w1"], params["b1"]))
+    logp = jax.nn.log_softmax(dense(h2, params["w_out"], params["b_out"]))
+    return -jnp.take_along_axis(logp, batch["labels"][..., None],
+                                -1)[..., 0].mean()
+
+
+def _sharded_sage_gaps(drop_exchange: bool):
+    """One sampled train step of the deployed configuration (PALLAS_CONFIG:
+    the FAST-GAS kernel in interpret mode, 4-seed chunks, scheduled) on a
+    4-chip owner-sharded table of a tiny R-MAT graph, against the plain
+    float32 reference on the same batch and weights. Returns the relative
+    gap of the loss and, per parameter, the largest gradient entry gap over
+    the leaf's largest entry.
+
+    ``drop_exchange`` plants the fault the comparison must catch: the
+    request broadcast hands each chip its own requests only (the others'
+    read as dead ids), so each chip aggregates only the rows it owns."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.common.config import TrainConfig
+    from repro.common.schema import init_params
+    from repro.configs import graphic_gcn
+    from repro.core.gcn import gcn_schema
+    from repro.data import GraphBatchStream
+    from repro.graph import rmat
+    from repro.launch.mesh import make_data_mesh
+    from repro.optim import adamw_init
+    from repro.train import make_sage_train_step
+
+    n, F, H, C, B, K1, K2 = 4, 16, 8, 5, 8, 3, 4
+    g = rmat(8, 4, seed=0)                        # 256 vertices, 1,024 edges
+    V = g.n_vertices
+    mesh = make_data_mesh(n)
+    cfg = dataclasses.replace(graphic_gcn.PALLAS_CONFIG, n_features=F,
+                              hidden=H, n_classes=C, fanout=K2,
+                              request_chunk=4)
+    # a clip no gradient reaches: AdamW's first moment after one step is
+    # (1 - beta1) times the raw gradient
+    tc = TrainConfig(learning_rate=1e-2, warmup_steps=0, grad_clip=1e9)
+    table = jax.random.normal(jax.random.PRNGKey(1), (V, F), jnp.float32)
+    feats = jax.device_put(table.reshape(n, V // n, F),
+                           NamedSharding(mesh, P("data")))
+    labels = np.random.default_rng(2).integers(0, C, V).astype(np.int32)
+    batch = GraphBatchStream(g, labels, n_parts=n, batch_per_part=B, k1=K1,
+                             k2=K2, seed=3).batch_at(0)
+    params = init_params(gcn_schema(cfg), jax.random.PRNGKey(0))
+    state = {"params": params, "opt": adamw_init(params, tc),
+             "step": jnp.zeros((), jnp.int32)}
+
+    gather = lax.all_gather
+
+    def own_requests_only(x, axis_name, **kw):
+        got = gather(x, axis_name, **kw)
+        mine = jnp.arange(got.shape[0]) == lax.axis_index(axis_name)
+        return jnp.where(mine.reshape((-1,) + (1,) * (got.ndim - 1)), got,
+                         -1)
+
+    if drop_exchange:
+        lax.all_gather = own_requests_only
+    try:
+        new, metrics = jax.jit(make_sage_train_step(cfg, tc, mesh=mesh))(
+            state, batch, feats)
+    finally:
+        lax.all_gather = gather
+    loss = float(metrics["total_loss"])
+    grads = {k: np.asarray(m) / (1 - tc.beta1)
+             for k, m in new["opt"]["m"].items()}
+    with jax.default_matmul_precision("highest"):
+        ref_loss, ref_grads = jax.value_and_grad(_plain_sage_loss)(
+            params, table, {k: jnp.asarray(v) for k, v in batch.items()})
+    gaps = {"loss": abs(loss - float(ref_loss)) / abs(float(ref_loss))}
+    for k, r in ref_grads.items():
+        r = np.asarray(r)
+        gaps[k] = float(np.max(np.abs(grads[k] - r)) / np.max(np.abs(r)))
+    return gaps
+
+
+# The program sums each mean in another order than the reference (the
+# owner-side partials, then the cross-chip combine) and runs its dense
+# layers on the CPU in float32, so loss and gradients differ from the
+# float32 reference by rounding alone: about 1e-7 of the value. The limits
+# leave two orders of magnitude for that; an aggregation that loses any
+# chip's rows moves the loss and every gradient by far more.
+SAGE_LOSS_RTOL, SAGE_GRAD_RTOL = 1e-5, 1e-4
+
+
+def case_sharded_sage_reference():
+    """The owner-sharded train step (request all_gather, owner-side find and
+    FAST-GAS reduce, partials all_to_all) equals the plain reference."""
+    gaps = _sharded_sage_gaps(drop_exchange=False)
+    print(gaps)
+    assert gaps["loss"] <= SAGE_LOSS_RTOL, gaps
+    assert max(v for k, v in gaps.items() if k != "loss") <= (
+        SAGE_GRAD_RTOL), gaps
+    print("sharded sage reference ok")
+
+
+def case_sharded_sage_no_exchange():
+    """The same comparison catches a step that drops the exchange."""
+    gaps = _sharded_sage_gaps(drop_exchange=True)
+    print(gaps)
+    assert gaps["loss"] > SAGE_LOSS_RTOL, gaps
+    assert all(v > SAGE_GRAD_RTOL for k, v in gaps.items()
+               if k.startswith("w")), gaps
+    print("sharded sage no-exchange caught ok")
+
+
+def case_exchange_scope():
+    """On a 4-chip mesh every cross-chip collective of the sampled path is
+    named ``cgtrans.exchange``: the request all_gather and the partials'
+    all_to_all of the deployed train step (its backward ships nothing: no
+    gradient reaches the table), and the transposed all_to_all of a
+    gradient taken through the aggregation into the table."""
+    import dataclasses
+    import re
+    import jax
+    import jax.numpy as jnp
+    from repro.common.config import TrainConfig
+    from repro.common.schema import init_params
+    from repro.configs import graphic_gcn
+    from repro.core import cgtrans
+    from repro.core.gcn import gcn_schema
+    from repro.data import GraphBatchStream
+    from repro.graph import rmat
+    from repro.launch.mesh import make_data_mesh
+    from repro.optim import adamw_init
+    from repro.train import make_sage_train_step
+
+    n, F = 4, 16
+    mesh = make_data_mesh(n)
+    g = rmat(8, 4, seed=0)
+    feats = jax.random.normal(jax.random.PRNGKey(1),
+                              (n, g.n_vertices // n, F))
+    cfg = dataclasses.replace(graphic_gcn.PALLAS_CONFIG, n_features=F,
+                              hidden=8, n_classes=5, fanout=3,
+                              request_chunk=2)
+    tc = TrainConfig(learning_rate=1e-2, warmup_steps=0)
+    params = init_params(gcn_schema(cfg), jax.random.PRNGKey(0))
+    state = {"params": params, "opt": adamw_init(params, tc),
+             "step": jnp.zeros((), jnp.int32)}
+    batch = GraphBatchStream(g, np.zeros(g.n_vertices, np.int32), n_parts=n,
+                             batch_per_part=4, k1=3, k2=3,
+                             seed=3).batch_at(0)
+    step = jax.jit(make_sage_train_step(cfg, tc, mesh=mesh))
+    nb, mk = jnp.asarray(batch["nbrs2"]), jnp.asarray(batch["mask2"])
+    into_table = jax.jit(jax.grad(lambda f: cgtrans.aggregate_sampled(
+        f, nb, mk, mesh=mesh, impl=cfg.impl, request_chunk=2).sum()))
+    coll = re.compile(r" (all-gather|all-to-all|reduce-scatter)(?:-start)?\(")
+    for name, compiled in (
+            ("train step", step.lower(state, batch, feats).compile()),
+            ("table gradient", into_table.lower(feats).compile())):
+        ops = []
+        for line in compiled.as_text().splitlines():
+            m = coll.search(line)
+            if m:
+                stack = re.search(r'op_name="([^"]*)"', line)
+                ops.append((m.group(1), stack.group(1) if stack else ""))
+        print(name, ops)
+        assert {"all-gather", "all-to-all"} <= {op for op, _ in ops}, ops
+        unnamed = [o for o in ops if cgtrans.EXCHANGE_SCOPE
+                   not in o[1].split("/")]
+        assert not unnamed, (name, unnamed)
+        if name == "table gradient":
+            assert any(op == "all-to-all" and "transpose(" in stack
+                       for op, stack in ops), ops
+    print("exchange scope ok")
+
+
 CASES = {n[len("case_"):]: f for n, f in list(globals().items())
          if n.startswith("case_")}
 

@@ -54,3 +54,15 @@ def test_distributed_sage_training():
 
 def test_pipeline_parallel():
     assert "ok" in _run("pipeline_parallel")
+
+
+def test_sharded_sage_matches_plain_reference():
+    """One deployed train step on a 4-chip owner-sharded table equals a plain
+    float32 jax.numpy GraphSAGE on the same batch, loss and gradients."""
+    assert "ok" in _run("sharded_sage_reference", devices=4)
+
+
+def test_sharded_sage_without_exchange_fails_the_comparison():
+    """The same comparison refuses a step in which each chip aggregates only
+    the rows it owns."""
+    assert "caught ok" in _run("sharded_sage_no_exchange", devices=4)

@@ -2,7 +2,8 @@
 
 Device work is named with ``jax.named_scope`` (it lands in each HLO op's
 ``metadata.op_name``): ``gas.find``, ``gas.schedule``, ``gas.reduce``,
-``cgtrans.chunk`` and ``gcn.combine``. Host work is named with
+``cgtrans.chunk`` and ``gcn.combine``, and on a sharded mesh
+``cgtrans.exchange`` around every cross-chip collective. Host work is named with
 ``jax.profiler.TraceAnnotation``: ``repro.data.sample`` around a minibatch
 draw, ``repro.train.step`` around a training step, the next batch's draw and
 the wait, and ``repro.train.wait`` around the wait alone. A trace
@@ -12,6 +13,7 @@ stack, so the three GAS scopes must never nest inside one another.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import re
 
@@ -23,12 +25,15 @@ import pytest
 from repro.common.config import TrainConfig
 from repro.common.schema import init_params
 from repro.configs import graphic_gcn
+from repro.core import cgtrans
 from repro.core.gcn import gcn_forward_full, gcn_schema
 from repro.data import GraphBatchStream
 from repro.graph import COOGraph, partition_by_src
 from repro.launch.mesh import make_data_mesh
 from repro.optim import adamw_init
 from repro.train import make_sage_train_step, train_loop
+
+from _dist import run_distributed_case
 
 SCOPES = ("gas.find", "gas.schedule", "gas.reduce", "cgtrans.chunk",
           "gcn.combine")
@@ -111,6 +116,39 @@ def test_gas_scopes_never_nest(programs, program):
     nested = [n for n in programs[program]
               if len({m for m in SCOPE_RE.findall(n) if m in GAS}) > 1]
     assert not nested, nested[:5]
+
+
+@pytest.mark.parametrize("program", ["train", "infer"])
+def test_one_chip_programs_carry_no_exchange(programs, program):
+    named = [n for n in programs[program]
+             if cgtrans.EXCHANGE_SCOPE in n.split("/")]
+    assert not named, named[:5]
+
+
+def test_exchange_scope_is_never_entered_on_one_chip(train, monkeypatch):
+    """On a one-device mesh no collective is traced, so the exchange scope
+    is never entered: the one-chip programs are what they are without it."""
+    step, state, feats, stream = train
+    entered = []
+
+    def counted():
+        entered.append(1)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(cgtrans, "_exchange", counted)
+    jax.jit(step.__wrapped__).lower(state, stream.batch_at(0),
+                                    feats).compile()
+    _infer_ops()
+    assert not entered
+
+
+@pytest.mark.distributed
+def test_exchange_scope_names_every_collective_on_four_chips():
+    """Each all-gather and all-to-all of the sharded train step, and the
+    transposed all-to-all of a gradient into the table, carries the scope
+    (``tests/distributed_cases.py exchange_scope``, four CPU devices)."""
+    assert "exchange scope ok" in run_distributed_case("exchange_scope",
+                                                       devices=4)
 
 
 def _host_events(path: str, names):
