@@ -15,7 +15,9 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Dict, Iterator, Optional
+import threading
+from concurrent.futures import ThreadPoolExecutor
+from typing import Callable, Dict, Iterator, Optional
 
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -92,6 +94,48 @@ class ShardedTokenFiles:
             step += 1
 
 
+# Rows of one block of a hop's neighbour draw. At fan-out 50 a block's
+# float64 and int64 temporaries are 3.3 MB each: small enough for the heap
+# to hand back from one block to the next, where a whole hop's (84 MB each
+# at 208,896 rows) are mapped and page-faulted in anew for every batch.
+BLOCK_ROWS = 8192
+# The most threads that draw one hop's blocks at once; the host's other
+# cores stay with the JAX runtime.
+MAX_WORKERS = 8
+
+_pool: Optional[ThreadPoolExecutor] = None
+_pool_lock = threading.Lock()
+
+
+def _executor() -> ThreadPoolExecutor:
+    """The process's sampler pool, made on first use (its threads start as
+    work is handed to them)."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(os.cpu_count() or 1,
+                                       thread_name_prefix="repro-sample")
+        return _pool
+
+
+def _each_block(fn: Callable[[int], None], starts: range) -> None:
+    """``fn(start)`` for every block start, on ``min(blocks, cores,
+    MAX_WORKERS)`` threads; one worker runs them on the calling thread.
+    numpy releases the GIL in the random fill, the ufuncs and ``take``."""
+    workers = min(len(starts), os.cpu_count() or 1, MAX_WORKERS)
+    if workers <= 1:
+        for start in starts:
+            fn(start)
+        return
+
+    def share(w: int) -> None:
+        for start in starts[w::workers]:
+            fn(start)
+
+    for f in [_executor().submit(share, w) for w in range(workers)]:
+        f.result()
+
+
 @dataclasses.dataclass
 class GraphBatchStream:
     """Minibatch sampler for 2-layer GraphSAGE (ids only on the wire)."""
@@ -108,14 +152,41 @@ class GraphBatchStream:
         self.indptr, self.indices, _ = self.graph.to_csr()
 
     def _sample(self, rng, seeds: np.ndarray, k: int):
-        lo = self.indptr[seeds]
-        hi = self.indptr[seeds + 1]
-        deg = (hi - lo).astype(np.int64)
-        offs = (rng.random((len(seeds), k)) * np.maximum(deg, 1)[:, None]).astype(np.int64)
-        idx = np.minimum(lo[:, None] + offs, len(self.indices) - 1)
-        nbrs = self.indices[idx].astype(np.int32)
-        mask = np.broadcast_to(deg[:, None] > 0, nbrs.shape)
-        nbrs = np.where(mask, nbrs, seeds[:, None].astype(np.int32))
+        """k neighbours of each vertex of ``seeds``, uniform over its
+        out-edges with replacement; a vertex with none repeats itself,
+        masked off. Drawn in row blocks of ``BLOCK_ROWS``, each inside the
+        host span ``repro.data.sample.block``: the block that starts at row
+        ``a`` takes ``rng``'s uniforms from the ``a * k``-th on (one PCG64
+        output per float64), so the result is the one serial draw of all
+        ``n * k`` uniforms, after which ``rng`` is moved past them."""
+        n, k, rows = seeds.shape[0], int(k), BLOCK_ROWS
+        nbrs = np.empty((n, k), np.int32)
+        mask = np.empty((n, k), np.bool_)
+        state = rng.bit_generator.state
+
+        def block(start: int) -> None:
+            with TraceAnnotation("repro.data.sample.block"):
+                end = min(start + rows, n)
+                own = seeds[start:end]
+                lo = self.indptr[own]
+                deg = self.indptr[own + 1] - lo
+                bits = np.random.PCG64()
+                bits.state = state
+                bits.advance(start * k)
+                offs = np.random.Generator(bits).random((end - start, k))
+                offs *= np.maximum(deg, 1)[:, None]
+                idx = offs.astype(np.int64)
+                idx += lo[:, None]
+                # mode="clip" is the clamp at E - 1 (idx is never negative)
+                out = nbrs[start:end]
+                np.take(self.indices, idx, out=out, mode="clip")
+                has = deg > 0
+                mask[start:end] = has[:, None]
+                none = np.flatnonzero(~has)
+                out[none] = own[none, None]
+
+        _each_block(block, range(0, n, rows))
+        rng.bit_generator.advance(n * k)
         return nbrs, mask
 
     def batch_at(self, step: int) -> Dict[str, np.ndarray]:

@@ -5,7 +5,8 @@ Device work is named with ``jax.named_scope`` (it lands in each HLO op's
 ``cgtrans.chunk`` and ``gcn.combine``, and on a sharded mesh
 ``cgtrans.exchange`` around every cross-chip collective. Host work is named with
 ``jax.profiler.TraceAnnotation``: ``repro.data.sample`` around a minibatch
-draw, ``repro.train.step`` around a training step, the next batch's draw and
+draw, ``repro.data.sample.block`` around each row block of its neighbour
+draws (on whichever thread runs it), ``repro.train.step`` around a training step, the next batch's draw and
 the wait, and ``repro.train.wait`` around the wait alone. A trace
 reduction attributes each op to the innermost of the scopes in its name
 stack, so the three GAS scopes must never nest inside one another.
@@ -27,7 +28,7 @@ from repro.common.schema import init_params
 from repro.configs import graphic_gcn
 from repro.core import cgtrans
 from repro.core.gcn import gcn_forward_full, gcn_schema
-from repro.data import GraphBatchStream
+from repro.data import GraphBatchStream, pipeline
 from repro.graph import COOGraph, partition_by_src
 from repro.launch.mesh import make_data_mesh
 from repro.optim import adamw_init
@@ -185,3 +186,29 @@ def test_host_spans_one_per_step(train, tmp_path):
     assert waits[0][1] <= steps[0][1]
     # the first batch is pulled before any step
     assert samples[0][1] <= steps[0][0]
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_sample_blocks_per_hop(train, tmp_path, monkeypatch, workers):
+    """One draw holds ceil(rows / BLOCK_ROWS) block spans per hop, inside
+    its ``repro.data.sample``; hop 2's blocks start after hop 1's end."""
+    _, _, _, stream = train
+    rows = 3
+    monkeypatch.setattr(pipeline, "BLOCK_ROWS", rows)
+    monkeypatch.setattr(pipeline, "MAX_WORKERS", workers)
+    hop1 = stream.n_parts * stream.batch_per_part
+    hop2 = hop1 * (1 + stream.k1)
+    n1, n2 = -(-hop1 // rows), -(-hop2 // rows)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        stream.batch_at(0)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    events = _host_events(str(path), {"repro.data.sample",
+                                      "repro.data.sample.block"})
+    (sample,) = [e[1:] for e in events if e[0] == "repro.data.sample"]
+    blocks = [e[1:] for e in events if e[0] == "repro.data.sample.block"]
+    assert len(blocks) == n1 + n2 == 8
+    assert all(sample[0] <= s <= e <= sample[1] for s, e in blocks)
+    assert max(e for _, e in blocks[:n1]) <= min(s for s, _ in blocks[n1:])
